@@ -48,10 +48,14 @@ def default_counts() -> dict:
     }
 
 
-def _build_traffic(counts: dict):
+def build_traffic(counts: dict, forge=()):
     """(payloads, pk_table, messages): c-major payload stream of
     struct('<II')-framed (committee, aggregate_index) headers + the 96-byte
-    aggregate signature; pk_table[(c, s)] is that aggregate's pubkey tuple."""
+    aggregate signature; pk_table[(c, s)] is that aggregate's pubkey tuple.
+
+    `forge`: (c, s) aggregates whose signers sign the NEXT committee's
+    message instead — a valid signature over another message, which the
+    verifier must reject."""
     from consensus_specs_tpu.crypto import bls_sig
 
     C = counts["committees"]
@@ -72,12 +76,13 @@ def _build_traffic(counts: dict):
             lo = s * step
             hi = size if s == aps - 1 else min(size, lo + step)
             pk_table[(c, s)] = tuple(order_pks[lo:hi])
-            sig = bls_sig.Sign(sum(order_sks[lo:hi]), messages[c])
+            signed = messages[(c + 1) % C] if (c, s) in forge else messages[c]
+            sig = bls_sig.Sign(sum(order_sks[lo:hi]), signed)
             payloads.append(struct.pack("<II", c, s) + bytes(sig))
     return payloads, pk_table, messages
 
 
-def _make_classifier(pk_table: dict, messages: list):
+def make_classifier(pk_table: dict, messages: list):
     from consensus_specs_tpu.firehose import AttestationItem, ClassifyError
     from consensus_specs_tpu.parallel.gossip_driver import message_id
 
@@ -107,8 +112,8 @@ def run(counts: dict | None = None) -> dict:
     if counts is None:
         counts = default_counts()
     t0 = time.time()
-    payloads, pk_table, messages = _build_traffic(counts)
-    classify = _make_classifier(pk_table, messages)
+    payloads, pk_table, messages = build_traffic(counts)
+    classify = make_classifier(pk_table, messages)
     n_atts = len(payloads)
     print(f"# firehose host prep ({n_atts} aggregate attestations over "
           f"{counts['committees']} committees of {counts['committee_size']}): "

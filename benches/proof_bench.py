@@ -97,8 +97,8 @@ def _start_firehose_thread(counts: dict, stop: threading.Event):
     fh_counts = {"committees": counts["firehose_committees"],
                  "committee_size": counts["firehose_size"],
                  "atts_per_committee": counts["firehose_atts"], "rounds": 1}
-    payloads, pk_table, messages = fb._build_traffic(fh_counts)
-    classify = fb._make_classifier(pk_table, messages)
+    payloads, pk_table, messages = fb.build_traffic(fh_counts)
+    classify = fb.make_classifier(pk_table, messages)
     cfg = FirehoseConfig(batch_attestations=len(payloads),
                          max_pending=len(payloads), flush_deadline_s=30.0)
     reg = obs_metrics.MetricsRegistry()

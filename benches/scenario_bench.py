@@ -11,7 +11,7 @@ bidirectional-conformance evidence: a nonzero diff count fails the run).
 
 Usage: python benches/scenario_bench.py — one JSON line.
 BENCH_SCENARIO_SEED / BENCH_SCENARIO_EPOCHS size the lane (defaults:
-seed 1, 8 epochs — bounded for the bench-probe loop; the ≥2,000-slot
+seed 1, 8 epochs — bounded for the bench budget; the ≥2,000-slot
 soak lives in tests/test_scenarios.py under @slow).
 """
 import json

@@ -3,7 +3,7 @@
 Canonical means byte-identical across two dumps of equal registry state:
 sorted keys, fixed separators, no timestamps — if a consumer wants a
 timestamp it goes in the caller-supplied `meta` block, never injected here.
-The CI artifact diff and tools/bench_probe.py rely on this.
+The CI artifact diff relies on this.
 
 The two formats expose ONE value set. `snapshot_value_set` derives
 {series: float} from the JSON snapshot; `prometheus_value_set` parses the

@@ -11,10 +11,13 @@ Two attachment points inside jax, both observational:
   * the lowering log record "Compiling <fun_name> with global shapes and
     types <args>." (jax._src.interpreters.pxla) carries the kernel NAME and
     the abstract shapes — a logging.Handler parses it into per-kernel
-    counters (`compile_total{kernel=...}`) and a distinct-shape set;
+    counters (`compile_total{kernel=...}`) and a distinct-shape set. jax
+    0.9 names the module `jit(<fun_name>)`; `kernel_name` strips that
+    wrapper so the counters stay keyed by the bare function name;
   * `jax.monitoring`'s BACKEND_COMPILE_EVENT duration stream feeds a
-    `compile_seconds` histogram (no kernel attribution, but it is the
-    wall-clock the cache pressure actually costs).
+    `compile_seconds` histogram (the wall-clock the cache pressure
+    actually costs; a persistent-cache hit is timed too) and the
+    per-kernel seconds behind `kernel_seconds()`.
 
 jax is imported ONLY inside install(): off-device (or with jax absent) the
 module stays importable and install() degrades to a no-op tracker, the same
@@ -27,6 +30,7 @@ uninstall() just clears the global.
 from __future__ import annotations
 
 import logging
+import re
 import threading
 from typing import Optional
 
@@ -36,21 +40,36 @@ from .metrics import REGISTRY, MetricsRegistry
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _COMPILE_MSG_PREFIX = "Compiling %s"
+# jax 0.9 logs the module name as `jit(<fun_name>)` (older builds logged the
+# bare name, which passes through unchanged).
+_WRAPPED_NAME = re.compile(r"^(?:jit|pjit)\((.*)\)$")
+
+
+def kernel_name(module_name: str) -> str:
+    """The bare jitted function name of a compile record's module name."""
+    m = _WRAPPED_NAME.match(module_name)
+    return m.group(1) if m else module_name
 
 
 class _CompileLogHandler(logging.Handler):
     """Parses jax's per-compilation log records; attached to the pxla
     logger by install(). Never raises into jax's logging path."""
 
-    def __init__(self, tracker: "CompileTracker"):
+    def __init__(self, tracker: "CompileTracker", forward_level=None):
         super().__init__(level=logging.DEBUG)
         self._tracker = tracker
+        # install() opened the logger below its old level and stopped its
+        # propagation; records at or above the old level still go up
+        self._forward_level = forward_level
 
     def emit(self, record: logging.LogRecord) -> None:
         try:
+            if (self._forward_level is not None
+                    and record.levelno >= self._forward_level):
+                logging.getLogger(record.name).parent.handle(record)
             if not record.msg.startswith(_COMPILE_MSG_PREFIX) or not record.args:
                 return
-            kernel = str(record.args[0])
+            kernel = kernel_name(str(record.args[0]))
             shapes = str(record.args[1]) if len(record.args) > 1 else ""
             self._tracker._on_compile(kernel, shapes)
         except Exception:
@@ -61,7 +80,8 @@ def _monitoring_trampoline(event: str, duration: float, **kwargs) -> None:
     tracker = _TRACKER
     if tracker is None or event != BACKEND_COMPILE_EVENT:
         return
-    tracker._on_backend_compile(duration)
+    tracker._on_backend_compile(
+        duration, kernel_name(str(kwargs.get("fun_name", "?"))))
 
 
 _TRAMPOLINE_REGISTERED = False
@@ -81,6 +101,7 @@ class CompileTracker:
         self._lock = threading.Lock()
         self._counts: dict[str, int] = {}
         self._shapes: dict[str, set] = {}
+        self._seconds: dict[str, float] = {}
         self._handler: Optional[_CompileLogHandler] = None
         self._logger: Optional[logging.Logger] = None
         self._prev_level: Optional[int] = None
@@ -95,8 +116,10 @@ class CompileTracker:
         self.registry.counter("compile_total", kernel=kernel).inc()
         self.registry.gauge("compile_distinct_shapes", kernel=kernel).set(distinct)
 
-    def _on_backend_compile(self, duration: float) -> None:
+    def _on_backend_compile(self, duration: float, kernel: str) -> None:
         self.registry.histogram("compile_seconds").observe(duration)
+        with self._lock:
+            self._seconds[kernel] = self._seconds.get(kernel, 0.0) + duration
 
     # -- readout ---------------------------------------------------------------
 
@@ -109,6 +132,11 @@ class CompileTracker:
     def kernels(self) -> dict[str, int]:
         with self._lock:
             return dict(sorted(self._counts.items()))
+
+    def kernel_seconds(self) -> dict[str, float]:
+        """Backend compile seconds per kernel (cache hits included)."""
+        with self._lock:
+            return dict(sorted(self._seconds.items()))
 
     # -- lifecycle -------------------------------------------------------------
 
@@ -126,14 +154,19 @@ class CompileTracker:
             _TRAMPOLINE_REGISTERED = True
         if self._handler is None:
             logger = logging.getLogger(pxla.__name__)
-            self._handler = _CompileLogHandler(self)
             self._logger = logger
             self._prev_level = logger.level
             # The compile log is DEBUG unless jax_log_compiles; the logger
-            # must be opened up for the handler to see it. Propagation is
-            # left on — ancestor handlers keep their own level filters.
+            # must be opened up for the handler to see it. jax's own stderr
+            # handler on the "jax" logger prints whatever reaches it, so the
+            # opened logger stops propagating and the handler forwards only
+            # the records the ancestors saw before.
+            forward = None
             if logger.getEffectiveLevel() > logging.DEBUG:
+                forward = logger.getEffectiveLevel()
                 logger.setLevel(logging.DEBUG)
+                logger.propagate = False
+            self._handler = _CompileLogHandler(self, forward)
             logger.addHandler(self._handler)
         return self
 
@@ -145,6 +178,7 @@ class CompileTracker:
             self._logger.removeHandler(self._handler)
             if self._prev_level is not None:
                 self._logger.setLevel(self._prev_level)
+            self._logger.propagate = True
             self._handler = None
             self._logger = None
             self._prev_level = None

@@ -14,7 +14,7 @@ composes it across the mesh.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -22,26 +22,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..ops import bls12_jax as K
 from .mesh import DATA_AXIS
 
-
-from functools import lru_cache
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Compat shim: jax >= 0.6 exposes `jax.shard_map` with the `check_vma`
-    flag; older builds (<= 0.4.x) ship `jax.experimental.shard_map` where
-    the same replication checker is called `check_rep`. Both are disabled —
-    every per-device tail here recomputes an identical replicated reduce
-    from gathered partials, which the checker can't prove."""
-    try:
-        from jax import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+# check_vma=False on every shard_map below: each per-device tail recomputes
+# an identical replicated reduce from gathered partials, which the
+# replication checker can't prove.
 
 
 @lru_cache(maxsize=8)
@@ -50,10 +33,11 @@ def _mesh_reduce_fn(mesh):
     rebuilding the shard_map closure per call would recompile every time."""
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(), P(), P()),
+        check_vma=False,
     )
     def reduce_shards(X, Y, Z):
         px, py, pz = K.g1_sum_reduce((X, Y, Z))
@@ -113,10 +97,11 @@ def _mesh_rlc_fn(mesh, p2_is_neg_g1: bool):
     import jax.numpy as jnp
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=tuple([P(DATA_AXIS)] * 9),
         out_specs=P(),
+        check_vma=False,
     )
     def rlc_shards(qx, qy, px, py, q2x, q2y, p2x, p2y, zbits):
         a1x, a1y = K.rlc_randomize_g1(px, py, zbits)
@@ -197,10 +182,11 @@ def _mesh_rlc_grouped_fn(mesh):
     import jax.numpy as jnp
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=tuple([P(DATA_AXIS)] * 7) + (P(),),
         out_specs=P(),
+        check_vma=False,
     )
     def grouped_shards(qx, qy, px, py, q2x, q2y, zbits, seg_ids):
         d_local = qx[0].shape[0]  # D / n_devices distinct messages per device

@@ -370,7 +370,11 @@ def spawn_cluster(n_nodes: int, messages_per_node: int = 8,
                   base_port: int | None = None) -> list[tuple]:
     """Run one gossip round with one OS process per node (localhost TCP
     standing in for DCN). Returns the per-node reports sorted by node id;
-    convergence holds iff every report carries the same count and digest."""
+    convergence holds iff every report carries the same count and digest.
+
+    Host-only: each `spawn` child imports just this module, which never
+    imports JAX, so no child asks for the chip that the parent may hold
+    (one process per chip)."""
     import multiprocessing as mp
     import os
 

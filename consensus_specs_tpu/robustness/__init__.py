@@ -15,7 +15,7 @@ Four pieces (see each module's docstring):
 
 The whole package is jax-free at module level (tpulint import-layering:
 `robustness/` is in the jax_free set) so the pure-host consumers —
-crypto/bls.py, the gossip driver, tools/bench_probe.py — can import it
+crypto/bls.py, the gossip driver — can import it
 without dragging in a device runtime.
 """
 from . import breaker, checkpoint, faults, retry  # noqa: F401

@@ -1,7 +1,7 @@
 """Seeded, deterministic fault injection at the device-boundary seams.
 
 The resident pipeline crosses six trust boundaries where real deployments
-fail: the XLA dispatch (tunnel drops, preemptions), the EpochAux host
+fail: the XLA dispatch (a lost device, preemptions), the EpochAux host
 readout (torn or corrupted D2H copies), the registry write-back (a crash
 mid-reconstruction), the gossip wire (truncated frames from a dying
 peer), the verification scheduler's dispatch (`sched.dispatch` — the
@@ -268,10 +268,12 @@ def _make_exc(spec: FaultSpec, site: str, ix: int) -> Exception:
         try:
             # Deferred so this module stays importable without jax; the
             # real type exercises the name-based classification in retry.py.
+            # UNAVAILABLE: the transient status (compile and resource
+            # statuses are fatal there).
             from jax.errors import JaxRuntimeError
         except Exception:
             return TransientFault(msg)
-        return JaxRuntimeError(f"INTERNAL: {msg}")
+        return JaxRuntimeError(f"UNAVAILABLE: {msg}")
     return TransientFault(msg)
 
 

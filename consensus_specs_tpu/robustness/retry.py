@@ -2,23 +2,30 @@
 
 One policy surface for every seam that can fail transiently: the resident
 engine's dispatch and aux readout, the bridge's write-back staging, the
-deferred-BLS flush, the gossip sockets, and tools/bench_probe.py's TPU
-probe loop. Classification is centralized here so "what is worth retrying"
-is one decision, not five ad-hoc try/excepts:
+deferred-BLS flush, and the gossip sockets. Classification is centralized
+here so "what is worth retrying" is one decision, not five ad-hoc
+try/excepts:
 
   retryable   injected TransientFaults, IntegrityErrors (the device source
-              is intact — re-reading is safe), XlaRuntimeError (matched by
-              MRO *name* so this module never imports jax), socket/OS
-              timeouts, and anything carrying `retryable = True`.
+              is intact — re-reading is safe), runtime XlaRuntimeErrors
+              such as UNAVAILABLE (matched by MRO *name* so this module
+              never imports jax), socket/OS timeouts, and anything carrying
+              `retryable = True`.
   fatal       everything else — assertion failures, BLSVerificationError,
-              host-code bugs, and `FatalFault` (the injected hard crash).
+              host-code bugs, `FatalFault` (the injected hard crash), and
+              XlaRuntimeErrors that re-issuing cannot fix: a failed lowering
+              or compile, an unimplemented op, a bad argument, device
+              memory exhausted, an internal compiler error
+              (`is_compile_or_resource_error`). Those are never degraded to
+              the host path either: a program that does not compile or fit
+              on the chip must fail loudly, not answer on the CPU.
 
 Donation caveat: the jitted epoch programs donate their input pytree, so a
 dispatch that fails AFTER consuming its buffers cannot be re-issued — the
 second attempt would read deleted memory. The injection seams therefore
 fire BEFORE the real call (input intact, retry safe), and a genuine
-post-donation failure surfaces as a deleted-buffer XlaRuntimeError whose
-retry fails identically and falls through to degradation.
+post-donation failure surfaces as a deleted-buffer error whose retry fails
+identically and falls through to degradation.
 
 jax-free at module level (tpulint import-layering).
 """
@@ -37,6 +44,24 @@ from .faults import FaultInjected
 # by __mro__ name keeps this module importable without jax. JaxRuntimeError
 # is jax's alias whose underlying class is named XlaRuntimeError.
 _RETRYABLE_TYPE_NAMES = frozenset({"XlaRuntimeError", "JaxRuntimeError"})
+# XLA status codes (the message prefix "<STATUS>: ...") of errors that the
+# same program on the same device raises again on every attempt.
+_FATAL_XLA_STATUSES = ("INVALID_ARGUMENT", "UNIMPLEMENTED",
+                       "RESOURCE_EXHAUSTED", "INTERNAL")
+
+
+def _is_xla_runtime_error(exc: BaseException) -> bool:
+    return any(t.__name__ in _RETRYABLE_TYPE_NAMES for t in type(exc).__mro__)
+
+
+def is_compile_or_resource_error(exc: BaseException) -> bool:
+    """True for an XLA error that re-issuing cannot fix: one of the fatal
+    statuses, or any error raised while lowering or compiling."""
+    if not _is_xla_runtime_error(exc):
+        return False
+    msg = str(exc).lstrip()
+    return (msg.startswith(_FATAL_XLA_STATUSES)
+            or "compil" in msg.lower() or "lowering" in msg.lower())
 
 
 def is_retryable(exc: BaseException) -> bool:
@@ -46,14 +71,15 @@ def is_retryable(exc: BaseException) -> bool:
         return bool(marked)
     if isinstance(exc, (TimeoutError, ConnectionError, OSError)):
         return True
-    return any(t.__name__ in _RETRYABLE_TYPE_NAMES for t in type(exc).__mro__)
+    return _is_xla_runtime_error(exc) and not is_compile_or_resource_error(exc)
 
 
 def is_device_failure(exc: BaseException) -> bool:
     """Failures eligible for device→host degradation (circuit-breaker
     accounting): anything retryable plus injected fatals — a crashed
     dispatch is a *device* problem, not a host-code bug, even when it is
-    not worth re-issuing."""
+    not worth re-issuing. Compile and resource errors are neither: they
+    propagate to the caller."""
     return is_retryable(exc) or isinstance(exc, FaultInjected)
 
 

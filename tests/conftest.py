@@ -4,10 +4,10 @@ Multi-chip TPU hardware is not available in CI; sharded code paths
 (pjit/shard_map over a Mesh) are validated on 8 virtual CPU devices, mirroring
 how the driver's dryrun_multichip compile-checks the multi-chip path.
 
-The accelerator-avoidance dance (env override, plugin-factory drop, config
-update) lives in the shared helper consensus_specs_tpu.utils.backend.force_cpu
-— the same path __graft_entry__.dryrun_multichip and bench.py's debug lane
-use, so all TPU-free entry points pin the backend identically.
+The CPU pin (env override, plugin-factory drop, config update) lives in the
+shared helper consensus_specs_tpu.utils.backend.force_cpu — the same path
+__graft_entry__.dryrun_multichip uses, so all TPU-free entry points pin the
+backend identically. The chip is reached only through `chip_smoke.py`.
 """
 import os
 from pathlib import Path
@@ -20,7 +20,8 @@ jax = force_cpu(8)
 
 # Persistent XLA compilation cache: the CPU-run pairing kernels compile for
 # tens of seconds to minutes; cache them across runs so only the first-ever
-# run pays (VERDICT r2 item 7). Safe to delete any time.
+# run pays (VERDICT r2 item 7). JAX_COMPILATION_CACHE_DIR, when set, wins
+# over the fixed tests/.jax_cache default. Safe to delete any time.
 enable_compile_cache(str(Path(__file__).parent / ".jax_cache"))
 
 

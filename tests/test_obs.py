@@ -339,6 +339,7 @@ def test_recompile_fixed_shape_compiles_once():
         assert tracker.compiles("_obs_fixed_kernel") == 1
         assert tracker.distinct_shapes("_obs_fixed_kernel") == 1
         assert reg.counter_value("compile_total", kernel="_obs_fixed_kernel") == 1
+        assert tracker.kernel_seconds()["_obs_fixed_kernel"] > 0
     finally:
         tracker.uninstall()
 

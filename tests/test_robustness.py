@@ -24,6 +24,7 @@ from consensus_specs_tpu.robustness.faults import (
 from consensus_specs_tpu.robustness.retry import (
     RetryPolicy,
     call_with_retry,
+    is_compile_or_resource_error,
     is_device_failure,
     is_retryable,
 )
@@ -167,6 +168,131 @@ def test_classification():
     assert is_device_failure(FatalFault("x"))
     assert is_device_failure(FakeXla("x"))
     assert not is_device_failure(ValueError("x"))
+
+
+def _xla_error(msg):
+    from jax.errors import JaxRuntimeError
+
+    return JaxRuntimeError(msg)
+
+
+# What the TPU runtime raises for a program that cannot compile or fit:
+# re-issuing it fails the same way, and the host path would hide it.
+_FATAL_XLA_MESSAGES = [
+    "RESOURCE_EXHAUSTED: Out of memory allocating 17179869184 bytes.",
+    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+    "memory in memory space hbm.",
+    "INTERNAL: Mosaic failed to compile TPU kernel.",
+    "INVALID_ARGUMENT: Executable expected parameter 0 of size 8.",
+    "UNIMPLEMENTED: unsupported op on this platform.",
+    "Compilation failure: unsupported tiling.",
+]
+
+
+@pytest.mark.parametrize("msg", _FATAL_XLA_MESSAGES)
+def test_compile_and_resource_errors_are_fatal(msg):
+    exc = _xla_error(msg)
+    assert is_compile_or_resource_error(exc)
+    assert not is_retryable(exc)
+    assert not is_device_failure(exc)
+
+
+@pytest.mark.parametrize("msg", [
+    "UNAVAILABLE: device lost", "DEADLINE_EXCEEDED: transfer", "device gone"])
+def test_runtime_xla_errors_stay_retryable(msg):
+    exc = _xla_error(msg)
+    assert not is_compile_or_resource_error(exc)
+    assert is_retryable(exc) and is_device_failure(exc)
+
+
+def _raising_class(exc):
+    """Scheduler work class whose device execute always raises `exc`;
+    records how often each path ran."""
+    from consensus_specs_tpu.sched.classes import WorkClass
+
+    class RaisingClass(WorkClass):
+        name = "raising"
+        kinds = ("check",)
+        executed = 0
+        degraded = 0
+
+        def execute(self, requests):
+            self.executed += 1
+            raise exc
+
+        def execute_degraded(self, requests):
+            self.degraded += 1
+            return np.ones(len(requests), dtype=bool)
+
+    return RaisingClass()
+
+
+def _run_raising(exc):
+    from consensus_specs_tpu.obs.metrics import MetricsRegistry
+    from consensus_specs_tpu.sched import Request, Scheduler
+
+    wc = _raising_class(exc)
+    reg = MetricsRegistry()
+    sch = Scheduler(classes=[wc], registry=reg,
+                    retry_policy=RetryPolicy(max_attempts=4, base_delay=0.0,
+                                             max_delay=0.0))
+    h = sch.submit(Request(work_class="raising", kind="check", payload=()))
+    return wc, reg, sch, h
+
+
+@pytest.mark.parametrize("msg", [
+    "RESOURCE_EXHAUSTED: Out of memory allocating 17179869184 bytes.",
+    "INTERNAL: XLA:TPU compile permanent error.",
+])
+def test_sched_never_degrades_compile_or_resource_errors(msg):
+    from jax.errors import JaxRuntimeError
+
+    wc, reg, sch, h = _run_raising(_xla_error(msg))
+    with pytest.raises(JaxRuntimeError):
+        sch.flush("raising")
+    assert wc.executed == 1  # no re-issue of a program that cannot run
+    assert wc.degraded == 0  # and never answered on the host
+    assert reg.counter_value("sched_degraded_total", work_class="raising") == 0
+    assert sch.breaker("raising").events == []
+    with pytest.raises(JaxRuntimeError):
+        h.result()
+
+
+@pytest.mark.parametrize("make_exc", [
+    lambda: _xla_error("UNAVAILABLE: device lost"),
+    lambda: TransientFault("injected"),
+    lambda: FatalFault("injected"),
+], ids=["unavailable", "transient_fault", "fatal_fault"])
+def test_sched_still_degrades_unavailable_and_injected_faults(make_exc):
+    wc, reg, sch, h = _run_raising(make_exc())
+    sch.flush("raising")
+    assert h.result() is True
+    assert wc.degraded == 1
+    assert reg.counter_value("sched_degraded_total", work_class="raising") == 1
+
+
+def test_bridge_never_degrades_a_resource_error(monkeypatch):
+    """The epoch bridge re-raises a RESOURCE_EXHAUSTED dispatch instead of
+    running `spec.process_epoch` on the host."""
+    from jax.errors import JaxRuntimeError
+
+    from consensus_specs_tpu.engine import bridge
+
+    def oom(*args, **kwargs):
+        raise _xla_error("RESOURCE_EXHAUSTED: Out of memory in hbm.")
+
+    class _Spec:
+        host_epochs = 0
+
+        def process_epoch(self, state):
+            self.host_epochs += 1
+
+    monkeypatch.setattr(bridge, "_apply_epoch_device", oom)
+    spec, brk = _Spec(), CircuitBreaker(name="t")
+    with pytest.raises(JaxRuntimeError):
+        bridge.apply_epoch_via_engine(spec, object(), breaker=brk)
+    assert spec.host_epochs == 0
+    assert brk.degraded_epochs == 0 and brk.events == []
 
 
 def test_retry_policy_delay_growth_and_ceiling():

@@ -8,8 +8,7 @@ Four subcommands — three over the canonical JSON snapshot format
                canonical bytes, and Prometheus round-trip (the text
                exposition's value set must equal the JSON's). Exit 0 ok,
                1 invalid, 2 unreadable. CI runs this over every uploaded
-               artifact; tools/bench_probe.py runs it over the snapshot
-               persisted next to BENCH_LOCAL.json.
+               artifact.
   prom FILE    render the snapshot as Prometheus text exposition (stdout),
                for scraping/diffing with standard tooling.
   table FILE   human-oriented summary: counters and gauges sorted by
