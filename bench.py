@@ -136,10 +136,11 @@ def bench_bls() -> tuple[float, float, float, dict, dict]:
 def run_benches() -> dict:
     import contextlib
 
+    import jax
+
     from consensus_specs_tpu.obs import metrics as obs_metrics
     from consensus_specs_tpu.obs import recompile as obs_recompile
     from consensus_specs_tpu.obs import trace as obs_trace
-    from consensus_specs_tpu.utils.profiling import timed, timings, trace
 
     # Observability ON for the bench run: spans over every instrumented seam
     # plus the per-kernel recompile tracker, all feeding the process
@@ -151,63 +152,66 @@ def run_benches() -> dict:
     compile_tracker = obs_recompile.CompileTracker(
         registry=obs_metrics.REGISTRY).install()
     profile_dir = os.environ.get("BENCH_PROFILE_DIR")
-    ctx = trace(profile_dir) if profile_dir else contextlib.nullcontext()
+    ctx = jax.profiler.trace(profile_dir) if profile_dir else contextlib.nullcontext()
     with ctx:
-        with timed("bench_bls"):
+        with obs_trace.span("bench_bls"):
             vps, rlc_vps, compile_s, rlc_stages, bls_flush = bench_bls()
-        with timed("bench_epoch"):
+        with obs_trace.span("bench_epoch"):
             epoch_s = bench_epoch()
-        with timed("bench_attestations"):
+        with obs_trace.span("bench_attestations"):
             import benches.attestation_bench as att_bench
 
             att = att_bench.run()
-        with timed("bench_state_root"):
+        with obs_trace.span("bench_state_root"):
             import benches.state_root_bench as sr_bench
 
             sr = sr_bench.run(int(os.environ.get("BENCH_SR_VALIDATORS", N_VALIDATORS)))
-        with timed("bench_epoch_e2e"):
+        with obs_trace.span("bench_epoch_e2e"):
             import benches.epoch_e2e_bench as e2e_bench
 
             e2e = e2e_bench.run(int(os.environ.get("BENCH_E2E_VALIDATORS", N_VALIDATORS)))
-        with timed("bench_kzg"):
+        with obs_trace.span("bench_kzg"):
             import benches.kzg_bench as kzg_bench
 
             kzg_r = kzg_bench.run()
-        with timed("bench_msm"):
+        with obs_trace.span("bench_msm"):
             import benches.msm_bench as msm_bench
 
             msm_r = msm_bench.run()
-        with timed("bench_sync_aggregate"):
+        with obs_trace.span("bench_sync_aggregate"):
             import benches.sync_aggregate_bench as sync_bench
 
             sync_r = sync_bench.run()
-        with timed("bench_sched"):
+        with obs_trace.span("bench_sched"):
             import benches.sched_bench as sched_bench
 
             sched_r = sched_bench.run()
-        with timed("bench_firehose"):
+        with obs_trace.span("bench_firehose"):
             import benches.firehose_bench as firehose_bench
 
             fh_r = firehose_bench.run()
-        with timed("bench_scenario"):
+        with obs_trace.span("bench_scenario"):
             import benches.scenario_bench as scenario_bench
 
             scen_r = scenario_bench.run()
-        with timed("bench_proofs"):
+        with obs_trace.span("bench_proofs"):
             import benches.proof_bench as proof_bench
 
             proof_r = proof_bench.run()
-        with timed("bench_forkchoice"):
+        with obs_trace.span("bench_forkchoice"):
             import benches.forkchoice_bench as forkchoice_bench
 
             fc_r = forkchoice_bench.run()
-        with timed("bench_frontdoor"):
+        with obs_trace.span("bench_frontdoor"):
             import benches.frontdoor_bench as frontdoor_bench
 
             fd_r = frontdoor_bench.run()
     if profile_dir:
         print(f"# device trace written to {profile_dir}", file=sys.stderr)
-    print(f"# stage timings: {timings()}", file=sys.stderr)
+    stage_s = {key.split('"')[1]: round(h["sum"], 6)
+               for key, h in obs_metrics.REGISTRY.snapshot()["histograms"].items()
+               if key.startswith('span_seconds{span="bench_')}
+    print(f"# stage timings: {stage_s}", file=sys.stderr)
     tracer.uninstall()
     compile_tracker.uninstall()
     obs_digest = {

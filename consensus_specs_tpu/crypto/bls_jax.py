@@ -105,17 +105,34 @@ class QueuedCheck:
         self.p1, self.q1, self.p2, self.q2 = p1, q1, p2, q2
 
 
+def _signature_and_message(message: bytes, signature: bytes):
+    """(H(m)_aff, sig_aff), or None for an invalid signature (the point at
+    infinity is never valid here)."""
+    try:
+        with _obs_trace.span("bls.prep.sig_decode"):
+            sig = g2_from_bytes(bytes(signature))
+    except ValueError:
+        return None
+    if sig is None:
+        return None
+    with _obs_trace.span("bls.prep.hash_to_curve"):
+        hm = hash_to_curve_g2(bytes(message))
+    return hm, sig
+
+
 def _decompress_inputs(pubkey: bytes, message: bytes, signature: bytes):
     """(pk_aff, H(m)_aff, sig_aff) or None if any input is invalid."""
     try:
-        pk = g1_from_bytes(bytes(pubkey))
-        sig = g2_from_bytes(bytes(signature))
+        with _obs_trace.span("bls.prep.pk_decode"):
+            pk = g1_from_bytes(bytes(pubkey))
     except ValueError:
         return None
-    if pk is None or sig is None:  # point at infinity is never valid here
+    if pk is None:
         return None
-    hm = hash_to_curve_g2(bytes(message))
-    return pk, hm, sig
+    hm_sig = _signature_and_message(message, signature)
+    if hm_sig is None:
+        return None
+    return (pk, *hm_sig)
 
 
 def make_verify_check(pubkey, message, signature) -> QueuedCheck | None:
@@ -227,29 +244,32 @@ def _aggregate_pubkeys_device_impl(pubkeys_bytes: list):
     affs: list = []
     cold_idx: list = []
     try:
-        for i, pk in enumerate(pubkeys_bytes):
-            pk = bytes(pk)
-            hit = _PK_VALIDATED.get(pk)
-            if hit is not None:
-                affs.append(hit)
-                continue
-            aff = oracle.g1_from_bytes(pk, subgroup_check=False)
-            if aff is None:
-                return ("inf_member",)
-            affs.append(aff)
-            cold_idx.append(i)
+        with _obs_trace.span("bls.aggregate.decode", keys=len(pubkeys_bytes)):
+            for i, pk in enumerate(pubkeys_bytes):
+                pk = bytes(pk)
+                hit = _PK_VALIDATED.get(pk)
+                if hit is not None:
+                    affs.append(hit)
+                    continue
+                aff = oracle.g1_from_bytes(pk, subgroup_check=False)
+                if aff is None:
+                    return ("inf_member",)
+                affs.append(aff)
+                cold_idx.append(i)
     except ValueError as e:
         return ("bad_encoding", str(e))
     if cold_idx:
-        ok = K.g1_subgroup_check_device([affs[i] for i in cold_idx])
-        if not bool(ok.all()):
-            return ("bad_encoding", "G1 point not in r-subgroup")
+        with _obs_trace.span("bls.aggregate.subgroup", keys=len(cold_idx)):
+            ok = K.g1_subgroup_check_device([affs[i] for i in cold_idx])
+            if not bool(ok.all()):
+                return ("bad_encoding", "G1 point not in r-subgroup")
         for i in cold_idx:
             if len(_PK_VALIDATED) >= _PK_VALIDATED_MAX:
                 _PK_VALIDATED.pop(next(iter(_PK_VALIDATED)))
             _PK_VALIDATED[bytes(pubkeys_bytes[i])] = affs[i]
         reg.counter("bls_pubkey_subgroup_device_total").inc(len(cold_idx))
-    total = K.g1_aggregate_device(affs)
+    with _obs_trace.span("bls.aggregate.device", keys=len(affs)):
+        total = K.g1_aggregate_device(affs)
     reg.counter("bls_pubkey_aggregate_device_total").inc()
     reg.counter("bls_pubkey_aggregate_device_keys_total").inc(len(affs))
     if total is None:
@@ -262,18 +282,16 @@ def make_fast_aggregate_check(pubkeys, message, signature) -> QueuedCheck | None
     if len(pubkeys) == 0:
         return None
     try:
-        agg = _aggregate_pubkeys_affine([bytes(pk) for pk in pubkeys])
+        with _obs_trace.span("bls.prep.aggregate", keys=len(pubkeys)):
+            agg = _aggregate_pubkeys_affine([bytes(pk) for pk in pubkeys])
     except ValueError:
         return None
     if agg is None:
         return None
-    try:
-        sig = g2_from_bytes(bytes(signature))
-    except ValueError:
+    hm_sig = _signature_and_message(message, signature)
+    if hm_sig is None:
         return None
-    if sig is None:
-        return None
-    hm = hash_to_curve_g2(bytes(message))
+    hm, sig = hm_sig
     return QueuedCheck(agg, hm, _NEG_G1, sig)
 
 
@@ -447,9 +465,9 @@ def _device_check_all(p1s, q1s, p2s, q2s) -> bool:
         if len(set(q1s)) < n:
             with _obs_trace.span("bls.flush.pack", path="rlc_grouped"):
                 b_n, b_d, args, seg_ids = _pack_grouped_args(p1s, q1s, q2s)
-            with _obs_trace.span("bls.flush.ladder", path="rlc_grouped"):
+            with _obs_trace.span("bls.flush.scalars", path="rlc_grouped"):
                 z = random_zbits(b_n)
-            with _obs_trace.span("bls.flush.miller", path="rlc_grouped"):
+            with _obs_trace.span("bls.flush.device", path="rlc_grouped"):
                 ok = K.pairing_check_rlc(*args, None, None, z,
                                          p2_is_neg_g1=True, seg_ids=seg_ids)
                 result = bool(np.asarray(jax.device_get(ok)))
@@ -458,9 +476,9 @@ def _device_check_all(p1s, q1s, p2s, q2s) -> bool:
         else:
             with _obs_trace.span("bls.flush.pack", path="rlc"):
                 b, args = _pack_pairing_args(p1s, q1s, p2s, q2s)
-            with _obs_trace.span("bls.flush.ladder", path="rlc"):
+            with _obs_trace.span("bls.flush.scalars", path="rlc"):
                 z = random_zbits(b)
-            with _obs_trace.span("bls.flush.miller", path="rlc"):
+            with _obs_trace.span("bls.flush.device", path="rlc"):
                 ok = K.pairing_check_rlc(*args, z, p2_is_neg_g1=True)
                 result = bool(np.asarray(jax.device_get(ok)))
             record_flush("rlc", items=b, distinct=b, miller_loops=b + 1)
@@ -484,7 +502,8 @@ def run_checks(checks) -> np.ndarray:
             out[i] = True
         return out
     # small batch, or the randomized check failed: per-item attribution
-    res = _device_check(*cols)
+    with _obs_trace.span("bls.flush.attribute", checks=len(live)):
+        res = _device_check(*cols)
     for (i, _), ok in zip(live, res):
         out[i] = bool(ok)
     return out

@@ -246,46 +246,47 @@ class ResidentEpochEngine:
         given the (seg,) aux flag arrays. Shared by step_epoch (seg=1) and
         run_epochs — the deferral-correctness argument lives on run_epochs."""
         seg = len(eth1_resets)
-        if dirty_cols is not None:
-            self._dirty |= np.asarray(dirty_cols).any(axis=0)
-        else:
-            self._dirty[:] = True  # unknown provenance: assume everything moved
-        self._epochs_since_sync += seg
-        if not advance_slots:
-            # per-slot mode: the mirror sits at the epoch's LAST slot and
-            # advance_slot increments it after this returns
-            assert seg == 1
-        if eth1_resets.any():
-            self.state.eth1_data_votes = type(self.state.eth1_data_votes)()
-        if hist_appends.any():
-            root = bridge.sched_historical_batch_root(
-                self.dev.block_roots, self.dev.state_roots)
-            for _ in range(int(hist_appends.sum())):
-                self.state.historical_roots.append(self.spec.Root(root))
-        if sync_updates.any():
-            # segment slicing guarantees the rotation fires only at the
-            # segment's LAST epoch, so device columns are current for it.
-            # In both modes the mirror sits at the last slot of the epoch
-            # preceding the rotation when _rotate runs (its next_epoch =
-            # slot//SPE + 1 = the epoch being entered).
-            assert sync_updates[-1] and int(sync_updates.sum()) == 1
-            if advance_slots:
-                self.state.slot += self.spec.SLOTS_PER_EPOCH * (seg - 1)
-            self._rotate_sync_committees_resident()
-            if advance_slots:
-                self.state.slot += self.spec.SLOTS_PER_EPOCH
-        elif advance_slots:
-            self.state.slot += self.spec.SLOTS_PER_EPOCH * seg
-        # root-cache refreshes are LAZY: state_root() drains the owed epochs
-        # so steps stay pure for callers that never ask for roots. Segments
-        # are contiguous, so (last stepped epoch, count) identifies every
-        # touched randao/slashings row — the epoch is pinned HERE, as "the
-        # epoch just entered": post-advance slot//SPE, or (slot+1)//SPE when
-        # advance_slot still owes the +1.
-        self._pending_epochs += seg
-        slot = int(self.state.slot)
-        self._pending_last_epoch = (
-            slot if advance_slots else slot + 1) // self.cfg.slots_per_epoch
+        with _obs_trace.span("engine.epilogue", epochs=seg):
+            if dirty_cols is not None:
+                self._dirty |= np.asarray(dirty_cols).any(axis=0)
+            else:
+                self._dirty[:] = True  # unknown provenance: assume everything moved
+            self._epochs_since_sync += seg
+            if not advance_slots:
+                # per-slot mode: the mirror sits at the epoch's LAST slot and
+                # advance_slot increments it after this returns
+                assert seg == 1
+            if eth1_resets.any():
+                self.state.eth1_data_votes = type(self.state.eth1_data_votes)()
+            if hist_appends.any():
+                root = bridge.sched_historical_batch_root(
+                    self.dev.block_roots, self.dev.state_roots)
+                for _ in range(int(hist_appends.sum())):
+                    self.state.historical_roots.append(self.spec.Root(root))
+            if sync_updates.any():
+                # segment slicing guarantees the rotation fires only at the
+                # segment's LAST epoch, so device columns are current for it.
+                # In both modes the mirror sits at the last slot of the epoch
+                # preceding the rotation when _rotate runs (its next_epoch =
+                # slot//SPE + 1 = the epoch being entered).
+                assert sync_updates[-1] and int(sync_updates.sum()) == 1
+                if advance_slots:
+                    self.state.slot += self.spec.SLOTS_PER_EPOCH * (seg - 1)
+                self._rotate_sync_committees_resident()
+                if advance_slots:
+                    self.state.slot += self.spec.SLOTS_PER_EPOCH
+            elif advance_slots:
+                self.state.slot += self.spec.SLOTS_PER_EPOCH * seg
+            # root-cache refreshes are LAZY: state_root() drains the owed epochs
+            # so steps stay pure for callers that never ask for roots. Segments
+            # are contiguous, so (last stepped epoch, count) identifies every
+            # touched randao/slashings row — the epoch is pinned HERE, as "the
+            # epoch just entered": post-advance slot//SPE, or (slot+1)//SPE when
+            # advance_slot still owes the +1.
+            self._pending_epochs += seg
+            slot = int(self.state.slot)
+            self._pending_last_epoch = (
+                slot if advance_slots else slot + 1) // self.cfg.slots_per_epoch
 
     def run_epochs(self, k: int) -> None:
         """k epoch transitions in as few device launches as possible.
@@ -444,21 +445,27 @@ class ResidentEpochEngine:
         from .incremental_root import IncrementalStateRoot
         from .state_root import assemble_state_root, validator_static_leaves
 
-        self._flush_pending()
-        if self._inc is None:
-            if not hasattr(self, "_static_leaves"):
-                self._static_leaves = jnp.asarray(validator_static_leaves(self.state))
-            self._inc = IncrementalStateRoot(self.dev, self._static_leaves)
-        elif self._pending_epochs:
-            self._inc.refresh_after_epochs(
-                self.dev,
-                last_epoch=self._pending_last_epoch,
-                count=self._pending_epochs,
-                epochs_per_historical_vector=self.cfg.epochs_per_historical_vector,
-            )
-        self._pending_epochs = 0
-        roots = jax.device_get(self._inc.device_roots(int(self.state.slot)))
-        return assemble_state_root(self.spec, self.state, roots)
+        self._flush_pending()  # the deferred epilogue, under its own spans
+        with _obs_trace.span("engine.state_root"):
+            with _obs_trace.span("engine.root_refresh",
+                                 epochs=self._pending_epochs):
+                if self._inc is None:
+                    if not hasattr(self, "_static_leaves"):
+                        self._static_leaves = jnp.asarray(
+                            validator_static_leaves(self.state))
+                    self._inc = IncrementalStateRoot(self.dev, self._static_leaves)
+                elif self._pending_epochs:
+                    self._inc.refresh_after_epochs(
+                        self.dev,
+                        last_epoch=self._pending_last_epoch,
+                        count=self._pending_epochs,
+                        epochs_per_historical_vector=self.cfg.epochs_per_historical_vector,
+                    )
+                self._pending_epochs = 0
+            with _obs_trace.span("engine.root_readout"):
+                roots = jax.device_get(self._inc.device_roots(int(self.state.slot)))
+            with _obs_trace.span("engine.root_assemble"):
+                return assemble_state_root(self.spec, self.state, roots)
 
     def advance_slot(self) -> None:
         """`process_slot` (+ the epoch transition at boundaries) against the

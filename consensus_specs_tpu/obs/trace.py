@@ -27,9 +27,18 @@ timeline exporter (obs/timeline.py) renders into per-thread lanes with
 flow events following a request across them. All of it rides the same
 disabled-mode contract: no tracer ⇒ `span(...)` still returns the shared
 no-op singleton and nothing mints, links, or records.
+
+Profiler clock: while a Tracer is installed in a process that has already
+imported jax, each span also opens and closes a profiler host annotation
+of the same name (`jax.profiler.TraceAnnotation`), so a profiler trace
+shows the program's spans on the same clock as the device's programs and
+ops. Durations stay on the monotonic clock. obs never imports jax itself:
+a Tracer installed where jax is not loaded records spans without the
+annotations (`Tracer.profiler_annotations` is then False).
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Optional
@@ -68,7 +77,8 @@ class Span:
     """One live (or finished) span. Created only by an installed Tracer."""
 
     __slots__ = ("name", "attrs", "depth", "parent", "t_start", "duration",
-                 "status", "ctx", "links", "thread", "thread_id", "_tracer")
+                 "status", "ctx", "links", "thread", "thread_id", "_tracer",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict,
                  depth: int, parent: Optional[str],
@@ -85,6 +95,7 @@ class Span:
         self.thread = ""
         self.thread_id = 0
         self._tracer = tracer
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -102,11 +113,17 @@ class Span:
         th = threading.current_thread()
         self.thread = th.name
         self.thread_id = th.ident or 0
+        annotation = self._tracer._annotation
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         self.t_start = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.duration = time.monotonic() - self.t_start
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.status = "error"
             self.attrs.setdefault("exc", exc_type.__name__)
@@ -150,6 +167,12 @@ class Tracer:
         self.dropped = 0
         self._lock = threading.Lock()
         self._local = threading.local()
+        self._annotation = None  # the profiler bridge, resolved at install()
+
+    @property
+    def profiler_annotations(self) -> bool:
+        """Whether each span also writes a profiler host annotation."""
+        return self._annotation is not None
 
     # -- stack ----------------------------------------------------------------
 
@@ -212,6 +235,7 @@ class Tracer:
 
     def install(self) -> "Tracer":
         global _TRACER
+        self._annotation = _profiler_annotation()
         _TRACER = self
         return self
 
@@ -222,6 +246,13 @@ class Tracer:
 
 
 _TRACER: Optional[Tracer] = None
+
+
+def _profiler_annotation():
+    """`jax.profiler.TraceAnnotation` where jax is already imported, else
+    None: the bridge never imports jax."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
 
 
 def current_tracer() -> Optional[Tracer]:

@@ -24,6 +24,7 @@ import os
 
 import numpy as np
 
+from ..obs import trace as _obs_trace
 from . import bucketing
 from .api import Request
 
@@ -111,14 +112,15 @@ class BlsWorkClass(WorkClass):
 
         checks = []
         host: dict = {}
-        for i, r in enumerate(requests):
-            if r.kind == "verify":
-                checks.append(bls_jax.make_verify_check(*r.payload))
-            elif r.kind == "fast_aggregate":
-                checks.append(bls_jax.make_fast_aggregate_check(*r.payload))
-            else:  # aggregate_verify: distinct message per signer, host path
-                checks.append(None)
-                host[i] = bool(bls_sig.AggregateVerify(*r.payload))
+        with _obs_trace.span("bls.prep", checks=len(requests)):
+            for i, r in enumerate(requests):
+                if r.kind == "verify":
+                    checks.append(bls_jax.make_verify_check(*r.payload))
+                elif r.kind == "fast_aggregate":
+                    checks.append(bls_jax.make_fast_aggregate_check(*r.payload))
+                else:  # aggregate_verify: distinct message per signer, host path
+                    checks.append(None)
+                    host[i] = bool(bls_sig.AggregateVerify(*r.payload))
         dev = bls_jax.run_checks(checks)
         return np.asarray(
             [host[i] if i in host else bool(dev[i])

@@ -251,6 +251,77 @@ def test_expand_message_xmd_structure():
     assert expand_message_xmd(b"msg", b"DST-A", 96) == a
 
 
+# --- host-prep spans (crypto/bls_jax.py, sched/classes.py) -------------------
+
+
+@pytest.fixture
+def tracer():
+    from consensus_specs_tpu.obs import trace as obs_trace
+    from consensus_specs_tpu.obs.metrics import MetricsRegistry
+
+    tr = obs_trace.Tracer(registry=MetricsRegistry()).install()
+    try:
+        yield tr
+    finally:
+        tr.uninstall()
+
+
+def test_fast_aggregate_check_prep_spans(tracer):
+    """Below DEVICE_AGGREGATE_MIN keys the prep is host only: one span per
+    stage, in order, and the queued check is the aggregate's."""
+    from consensus_specs_tpu.crypto import bls_jax
+
+    sks = (SK1, SK2, SK3)
+    assert len(sks) < bls_jax.DEVICE_AGGREGATE_MIN
+    msg = b"prep spans fast aggregate"
+    check = bls_jax.make_fast_aggregate_check(
+        [bls.SkToPk(k) for k in sks], msg, bls.Sign(sum(sks), msg))
+    assert check is not None
+    assert [s["name"] for s in tracer.spans()] == [
+        "bls.prep.aggregate", "bls.prep.sig_decode", "bls.prep.hash_to_curve"]
+    assert tracer.spans("bls.prep.aggregate")[0]["attrs"]["keys"] == 3
+    assert all(s["status"] == "ok" for s in tracer.spans())
+
+
+def test_verify_check_prep_spans_and_a_bad_signature(tracer):
+    from consensus_specs_tpu.crypto import bls_jax
+
+    msg = b"prep spans verify"
+    assert bls_jax.make_verify_check(bls.SkToPk(SK1), msg, bls.Sign(SK1, msg)) is not None
+    assert [s["name"] for s in tracer.spans()] == [
+        "bls.prep.pk_decode", "bls.prep.sig_decode", "bls.prep.hash_to_curve"]
+    # an undecodable signature ends the prep in its decode span
+    assert bls_jax.make_verify_check(bls.SkToPk(SK1), msg, b"\xff" * 96) is None
+    last = tracer.spans()[-1]
+    assert last["name"] == "bls.prep.sig_decode" and last["status"] == "error"
+    assert len(tracer.spans("bls.prep.hash_to_curve")) == 1
+
+
+def test_bls_prep_span_holds_each_request_prep(tracer, monkeypatch):
+    """`bls.prep` wraps the work class's per-request prep loop; the device
+    check after it is outside (stubbed here: host only)."""
+    import numpy as np
+
+    from consensus_specs_tpu.crypto import bls_jax
+    from consensus_specs_tpu.sched.api import Request
+    from consensus_specs_tpu.sched.classes import BlsWorkClass
+
+    monkeypatch.setattr(bls_jax, "run_checks",
+                        lambda checks: np.ones(len(checks), dtype=bool))
+    msgs = [b"prep loop %d" % i for i in range(2)]
+    reqs = [Request(work_class="bls", kind="verify",
+                    payload=(bls.SkToPk(SK2), m, bls.Sign(SK2, m))) for m in msgs]
+    assert BlsWorkClass().execute(reqs).tolist() == [True, True]
+    (prep,) = tracer.spans("bls.prep")
+    assert prep["attrs"]["checks"] == 2 and prep["depth"] == 0
+    inner = [s for s in tracer.spans() if s["name"].startswith("bls.prep.")]
+    assert len(inner) == 6
+    assert all(s["parent"] == "bls.prep" and s["depth"] == 1 for s in inner)
+    assert all(prep["t_start"] <= s["t_start"]
+               and s["t_start"] + s["duration"] <= prep["t_start"] + prep["duration"]
+               for s in inner)
+
+
 # --- deferral-queue hygiene under flush failure (robustness PR) --------------
 
 def test_deferred_queue_resets_after_flush_failure():

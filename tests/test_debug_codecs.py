@@ -44,22 +44,3 @@ def test_serialization_roundtrip_random(spec):
         decoded = typ.decode_bytes(serialize(value))
         assert hash_tree_root(decoded) == hash_tree_root(value)
 
-
-def test_profiling_hooks_noop_safe():
-    """Tracing helpers must degrade gracefully with no profiler backend."""
-    from consensus_specs_tpu.utils.profiling import (
-        annotate, annotate_fn, reset_timings, timed, timings,
-    )
-
-    reset_timings()
-    with timed("unit"):
-        with annotate("inner"):
-            pass
-
-    @annotate_fn()
-    def f(x):
-        return x + 1
-
-    assert f(1) == 2
-    stats = timings()
-    assert stats["unit"]["count"] == 1 and stats["unit"]["total_s"] >= 0

@@ -387,6 +387,40 @@ def test_cold_committee_aggregation_routes_device_then_caches():
     assert check is not None and check.p1 == want
 
 
+def test_cold_committee_aggregation_spans():
+    """Under a tracer, the device aggregation shows its three stages inside
+    the prep's `bls.prep.aggregate` and the msm class's dispatch; a warm
+    committee (every key validated before) skips the subgroup stage."""
+    from consensus_specs_tpu.crypto import bls, bls_jax, bls_sig
+    from consensus_specs_tpu.obs import trace as obs_trace
+    from consensus_specs_tpu.obs.metrics import MetricsRegistry
+
+    sks = [79001 + i for i in range(40)]
+    pks = [bytes(bls_sig.SkToPk(sk)) for sk in sks]
+    msg = b"aggregation spans"
+    bls.clear_caches()
+    reset_default_scheduler()
+    tr = obs_trace.Tracer(registry=MetricsRegistry()).install()
+    try:
+        assert bls_jax.make_fast_aggregate_check(
+            pks, msg, bls_sig.Sign(sum(sks), msg)) is not None
+        assert bls_jax.make_fast_aggregate_check(
+            pks[1:], msg, bls_sig.Sign(sum(sks[1:]), msg)) is not None
+    finally:
+        tr.uninstall()
+    stages = ["bls.aggregate.decode", "bls.aggregate.subgroup", "bls.aggregate.device"]
+    names = [s["name"] for s in tr.spans()]
+    first = names[:names.index("bls.prep.aggregate") + 1]
+    assert first == stages + ["sched.dispatch", "bls.prep.aggregate"]
+    assert names.count("bls.aggregate.subgroup") == 1  # the second is warm
+    assert names.count("bls.aggregate.device") == 2
+    for s in tr.spans():
+        if s["name"] in stages:
+            assert s["parent"] == "sched.dispatch" and s["depth"] == 2
+    assert tr.spans("bls.aggregate.subgroup")[0]["attrs"]["keys"] == 40
+    assert [s["parent"] for s in tr.spans("sched.dispatch")] == ["bls.prep.aggregate"] * 2
+
+
 def test_cold_committee_hostile_members_reject_like_host():
     """Hostile first-sighting committees fail closed through the device
     lane: an infinity member and an on-curve-but-not-in-subgroup member

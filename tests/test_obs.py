@@ -295,6 +295,99 @@ def test_annotate_appends_known_list_keys_overwrites_others():
         tr.uninstall()
 
 
+# --- profiler bridge -----------------------------------------------------------
+
+
+def _profiled(tmp_path, body):
+    """Run `body` under a CPU profiler trace; the host events of the trace
+    as {name: [(line, start_ns, end_ns)]}."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                events.setdefault(e.name, []).append(
+                    (line.name, e.start_ns, e.start_ns + e.duration_ns))
+    return events
+
+
+def test_spans_reach_the_profiler_trace_nested(tmp_path):
+    """An installed tracer writes each span as a profiler host annotation
+    of the same name, nested as the spans are, on the profiler's clock."""
+    import jax  # noqa: F401  (the bridge binds only where jax is loaded)
+
+    tr = obs_trace.Tracer(registry=MetricsRegistry()).install()
+    assert tr.profiler_annotations
+
+    def body():
+        with obs_trace.span("bridge.outer"):
+            with obs_trace.span("bridge.inner"):
+                pass
+            with obs_trace.span("bridge.inner"):
+                pass
+
+    try:
+        events = _profiled(tmp_path, body)
+    finally:
+        tr.uninstall()
+    (outer,) = events["bridge.outer"]
+    inner = events["bridge.inner"]
+    assert len(inner) == 2
+    assert all(line == outer[0] and outer[1] <= s <= e <= outer[2]
+               for line, s, e in inner)
+    assert inner[0][2] <= inner[1][1]  # siblings in order, not overlapping
+    # the span ring still times on the monotonic clock
+    assert [s["name"] for s in tr.spans()] == ["bridge.inner", "bridge.inner",
+                                               "bridge.outer"]
+
+
+def test_no_tracer_writes_no_annotation(tmp_path):
+    """Disabled mode stays the shared no-op: nothing reaches the trace."""
+    assert obs_trace.current_tracer() is None
+
+    def body():
+        with obs_trace.span("bridge.disabled") as sp:
+            assert sp is obs_trace.NULL_SPAN
+
+    assert "bridge.disabled" not in _profiled(tmp_path, body)
+
+
+def test_tracer_without_jax_records_spans_and_imports_nothing():
+    """Installing a tracer where jax was never imported keeps the process
+    jax-free (no poisoning here: an import would succeed, and be seen)."""
+    code = """
+import sys
+
+from consensus_specs_tpu.obs import trace
+
+tr = trace.Tracer().install()
+assert not tr.profiler_annotations
+with trace.span("engine.dispatch"):
+    with trace.span("engine.aux_readout"):
+        pass
+tr.uninstall()
+assert [s["name"] for s in tr.spans()] == ["engine.aux_readout", "engine.dispatch"]
+assert "jax" not in sys.modules
+print("TRACER-NO-JAX-OK")
+"""
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, cwd=REPO)
+    assert res.returncode == 0, res.stderr
+    assert "TRACER-NO-JAX-OK" in res.stdout
+
+
 # --- LAST_FLUSH compatibility view -------------------------------------------
 
 

@@ -91,6 +91,41 @@ def test_resident_state_root_matches_host_tree(spec):
         bls.bls_active = was
 
 
+def test_state_root_and_epilogue_spans(spec):
+    """`engine.state_root` holds three children that partition it (refresh,
+    readout, assembly); the deferred epilogue it drains first is outside
+    it, one `engine.epilogue` for each serviced epoch."""
+    from consensus_specs_tpu.obs import trace as obs_trace
+    from consensus_specs_tpu.obs.metrics import MetricsRegistry
+
+    was = bls.bls_active
+    bls.bls_active = False
+    tr = obs_trace.Tracer(registry=MetricsRegistry()).install()
+    try:
+        eng = ResidentEpochEngine(spec, _prepared_state(spec, start_epoch=6, seed=5))
+        eng.state_root()  # the first build
+        eng.step_epoch()
+        eng.step_epoch()
+        eng.state_root()
+    finally:
+        tr.uninstall()
+        bls.bls_active = was
+    children = ("engine.root_refresh", "engine.root_readout", "engine.root_assemble")
+    roots = tr.spans("engine.state_root")
+    assert len(roots) == 2
+    for root in roots:
+        end = root["t_start"] + root["duration"]
+        kids = [s for s in tr.spans() if s["parent"] == "engine.state_root"
+                and root["t_start"] <= s["t_start"] <= end]
+        assert [k["name"] for k in kids] == list(children)
+        assert all(k["t_start"] + k["duration"] <= end and k["depth"] == 1 for k in kids)
+        assert sum(k["duration"] for k in kids) <= root["duration"]
+    assert [s["attrs"]["epochs"] for s in tr.spans("engine.root_refresh")] == [0, 2]
+    epilogues = tr.spans("engine.epilogue")
+    assert [s["attrs"]["epochs"] for s in epilogues] == [1, 1]
+    assert all(s["parent"] != "engine.state_root" for s in epilogues)
+
+
 def test_resident_state_root_bellatrix(spec):
     """The generic field-root assembly covers bellatrix's extra
     (host-owned) execution-payload-header field."""
