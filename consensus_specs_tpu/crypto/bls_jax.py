@@ -154,14 +154,76 @@ def make_verify_check(pubkey, message, signature) -> QueuedCheck | None:
 _AGG_CACHE: dict = {}
 _AGG_CACHE_MAX = 1 << 12
 
-# Device-validated pubkeys: compressed bytes -> affine pair, populated by
-# the batched device subgroup check in _aggregate_pubkeys_device_impl.
-# Kept separate from the g1_from_bytes lru_cache because an lru_cache can
-# only be filled by the wrapped call — and that call is exactly the host
-# 255-bit pt_mul this lane exists to avoid. Bounded FIFO; entries are the
-# same ~0.5 KB as g1_from_bytes's.
-_PK_VALIDATED: dict = {}
+
+class _ValidatedPubkeys:
+    """Device-validated pubkeys: compressed bytes -> (affine pair, its
+    Montgomery rows), filled by the batched device subgroup check in
+    _aggregate_pubkeys_device_impl. Kept apart from the g1_from_bytes
+    lru_cache because an lru_cache can only be filled by the wrapped call,
+    which is exactly the host 255-bit pt_mul this lane exists to avoid.
+
+    Each key takes one slot: its point in `points`, and its x and y rows
+    (`F.to_mont` of the coordinates, as the aggregation program reads
+    them) in one (`_PK_VALIDATED_MAX`, 2, NLIMBS) int32 table, so a set's
+    warm keys are one `np.take`. Bounded FIFO by slot reuse: a new key
+    takes the oldest slot, point and rows together. Memory at the cap of
+    2^16 keys: the table is 32 MiB (512 B a key), the points ~0.5 KB a
+    key more. The rows belong to the field backend they were encoded for;
+    a switch of backend empties the store."""
+
+    def __init__(self):
+        import threading
+
+        self._lock = threading.Lock()
+        self.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            self.slot_of: dict = {}
+            self.keys: list = []
+            self.points: list = []
+            self.rows = None  # allocated on the first insert
+            self.field = None  # the backend module the rows encode for
+            self.next = 0
+
+    def __len__(self) -> int:
+        return len(self.slot_of)
+
+    def lookup(self, field, pubkeys: list):
+        """(slot per key, None where cold; the warm keys' rows, gathered
+        in order as (n_warm, 2, NLIMBS)). Rows come out under the lock that
+        guards eviction, so they are the rows of the keys looked up."""
+        with self._lock:
+            if self.field is not field:
+                return [None] * len(pubkeys), None
+            slots = [self.slot_of.get(pk) for pk in pubkeys]
+            warm = np.fromiter((s for s in slots if s is not None), np.intp)
+            return slots, np.take(self.rows, warm, axis=0)
+
+    def insert(self, field, pubkeys: list, points: list, X, Y) -> None:
+        """Cache keys that passed the subgroup check, with their rows."""
+        with self._lock:
+            cap = _PK_VALIDATED_MAX
+            if self.field is not field or len(self.keys) != cap:
+                self.slot_of, self.next = {}, 0
+                self.keys, self.points = [None] * cap, [None] * cap
+                self.rows = np.zeros((cap, 2, field.NLIMBS), np.asarray(X).dtype)
+                self.field = field
+            for pk, pt, x, y in zip(pubkeys, points, X, Y):
+                if pk in self.slot_of:
+                    continue  # a key listed twice in one set
+                s = self.next
+                self.next = (s + 1) % len(self.keys)
+                old = self.keys[s]
+                if old is not None:
+                    del self.slot_of[old]
+                self.keys[s], self.points[s] = pk, pt
+                self.rows[s, 0], self.rows[s, 1] = x, y
+                self.slot_of[pk] = s
+
+
 _PK_VALIDATED_MAX = 1 << 16
+_PK_VALIDATED = _ValidatedPubkeys()
 
 
 def _aggregate_pubkeys_affine(pubkeys_bytes: list):
@@ -233,45 +295,54 @@ def _aggregate_pubkeys_device_impl(pubkeys_bytes: list):
 
     Keys never seen before decompress WITHOUT the host 255-bit subgroup
     pt_mul (bls12_381.py:590) and are validated in ONE batched device
-    ladder ([r]P == inf via g1_subgroup_check_device) — the firehose cold
+    ladder ([r]P == inf via g1_subgroup_check_rows) — the firehose cold
     lane's dominant cost (one ~4 ms host check per member, ~2.7 s per
     488-member committee) collapses to a single bucketed kernel launch.
     The sum itself is the all-ones-scalar MSM degenerate case: a plain
-    masked reduction tree (g1_aggregate_device), no windows needed."""
+    masked reduction tree (g1_aggregate_rows), no windows needed.
+
+    Each key is encoded into Montgomery rows once, when first validated:
+    a cold key's rows feed the subgroup check, are cached with its point
+    once it passes, and feed the sum; a warm key's rows come from the
+    cache (`bls_pubkey_row_hits_total`)."""
     from ..ops import bls12_jax as K
 
     reg = _obs_metrics.REGISTRY
-    affs: list = []
+    field = K.F
+    pks = [bytes(pk) for pk in pubkeys_bytes]
     cold_idx: list = []
+    cold_affs: list = []
     try:
-        with _obs_trace.span("bls.aggregate.decode", keys=len(pubkeys_bytes)):
-            for i, pk in enumerate(pubkeys_bytes):
-                pk = bytes(pk)
-                hit = _PK_VALIDATED.get(pk)
-                if hit is not None:
-                    affs.append(hit)
+        with _obs_trace.span("bls.aggregate.decode", keys=len(pks)):
+            slots, warm_rows = _PK_VALIDATED.lookup(field, pks)
+            for i, s in enumerate(slots):
+                if s is not None:
                     continue
-                aff = oracle.g1_from_bytes(pk, subgroup_check=False)
+                aff = oracle.g1_from_bytes(pks[i], subgroup_check=False)
                 if aff is None:
                     return ("inf_member",)
-                affs.append(aff)
                 cold_idx.append(i)
+                cold_affs.append(aff)
     except ValueError as e:
         return ("bad_encoding", str(e))
+    rows = np.empty((len(pks), 2, field.NLIMBS), np.asarray(field.ONE_MONT).dtype)
+    if warm_rows is not None:
+        rows[[i for i, s in enumerate(slots) if s is not None]] = warm_rows
     if cold_idx:
         with _obs_trace.span("bls.aggregate.subgroup", keys=len(cold_idx)):
-            ok = K.g1_subgroup_check_device([affs[i] for i in cold_idx])
+            cx = field.ints_to_mont_batch([a[0] for a in cold_affs])
+            cy = field.ints_to_mont_batch([a[1] for a in cold_affs])
+            ok = K.g1_subgroup_check_rows(cx, cy)
             if not bool(ok.all()):
                 return ("bad_encoding", "G1 point not in r-subgroup")
-        for i in cold_idx:
-            if len(_PK_VALIDATED) >= _PK_VALIDATED_MAX:
-                _PK_VALIDATED.pop(next(iter(_PK_VALIDATED)))
-            _PK_VALIDATED[bytes(pubkeys_bytes[i])] = affs[i]
+        _PK_VALIDATED.insert(field, [pks[i] for i in cold_idx], cold_affs, cx, cy)
+        rows[cold_idx, 0], rows[cold_idx, 1] = cx, cy
         reg.counter("bls_pubkey_subgroup_device_total").inc(len(cold_idx))
-    with _obs_trace.span("bls.aggregate.device", keys=len(affs)):
-        total = K.g1_aggregate_device(affs)
+    reg.counter("bls_pubkey_row_hits_total").inc(len(pks) - len(cold_idx))
+    with _obs_trace.span("bls.aggregate.device", keys=len(pks)):
+        total = K.g1_aggregate_rows(rows[:, 0], rows[:, 1])
     reg.counter("bls_pubkey_aggregate_device_total").inc()
-    reg.counter("bls_pubkey_aggregate_device_keys_total").inc(len(affs))
+    reg.counter("bls_pubkey_aggregate_device_keys_total").inc(len(pks))
     if total is None:
         return ("inf",)
     return ("point", total[0], total[1])

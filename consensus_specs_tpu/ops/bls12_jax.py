@@ -1399,22 +1399,26 @@ def g1_msm_device(points_aff, scalars, nbits: int,
     return (xj * zinv * zinv % P, yj * zinv * zinv * zinv % P)
 
 
-def g1_aggregate_device(points_aff):
-    """Σ_i P_i (all-ones MSM fast path): affine int pairs in, affine pair
-    out (None for an infinity sum). Pads to the pow2 bucket with Jacobian
-    zeros — the complete add absorbs them, so padding never perturbs the
-    sum."""
-    b = _msm_pow2_pad(len(points_aff))
-    pad = b - len(points_aff)
-    enc = F.ints_to_mont_batch
-    X = jnp.asarray(enc([p[0] for p in points_aff] + [0] * pad))
-    Y = jnp.asarray(enc([p[1] for p in points_aff] + [0] * pad))
-    ones = np.zeros(b, dtype=bool)
-    ones[: len(points_aff)] = True
-    Z = jnp.where(jnp.asarray(ones)[:, None],
-                  jnp.broadcast_to(jnp.asarray(F.ONE_MONT), X.shape),
-                  jnp.zeros_like(X)).astype(X.dtype)
-    sx, sy, sz = jax.device_get(_g1_aggregate_program(X, Y, Z))
+def _jacobian_rows(X, Y):
+    """(n, NLIMBS) Montgomery rows of affine coordinates -> Jacobian (X, Y,
+    Z) device operands padded to the pow2 bucket. Live rows get Z = 1;
+    pads are Jacobian zeros (all-zero rows), which the complete add
+    absorbs and [r]·inf == inf reports as in the subgroup. Built on the
+    host, so the only device work is the program the caller launches."""
+    n = len(X)
+    b = _msm_pow2_pad(n)
+    Xp = np.zeros((b, F.NLIMBS), dtype=np.asarray(X).dtype)
+    Yp = np.zeros_like(Xp)
+    Zp = np.zeros_like(Xp)
+    Xp[:n], Yp[:n], Zp[:n] = X, Y, F.ONE_MONT
+    return jnp.asarray(Xp), jnp.asarray(Yp), jnp.asarray(Zp)
+
+
+def g1_aggregate_rows(X, Y):
+    """Σ_i P_i (all-ones MSM fast path) over points given as Montgomery
+    rows (F.ints_to_mont_batch of their affine x and y); affine pair out,
+    None for an infinity sum."""
+    sx, sy, sz = jax.device_get(_g1_aggregate_program(*_jacobian_rows(X, Y)))
     unmont = lambda v: F.from_mont_int(np.asarray(v).reshape(-1, F.NLIMBS)[0])
     xj, yj, zj = unmont(sx), unmont(sy), unmont(sz)
     if zj == 0:
@@ -1423,25 +1427,29 @@ def g1_aggregate_device(points_aff):
     return (xj * zinv * zinv % P, yj * zinv * zinv * zinv % P)
 
 
-def g1_subgroup_check_device(points_aff) -> np.ndarray:
-    """r-subgroup membership per affine point, batched: (n,) bool. The
-    255-bit fixed scalar r is broadcast across the bucket-padded batch
-    (pads are Jacobian zeros — [r]·inf == inf reports True and is
-    discarded)."""
-    n = len(points_aff)
-    b = _msm_pow2_pad(n)
-    pad = b - n
+def g1_subgroup_check_rows(X, Y) -> np.ndarray:
+    """r-subgroup membership per point given as Montgomery rows, batched:
+    (n,) bool. The 255-bit fixed scalar r is broadcast across the
+    bucket-padded batch (pad results are discarded)."""
+    Xp, Yp, Zp = _jacobian_rows(X, Y)
+    bits = jnp.asarray(np.broadcast_to(_r_order_bits(), (Xp.shape[0], 255)))
+    ok = jax.device_get(_g1_subgroup_program(Xp, Yp, Zp, bits))
+    return np.asarray(ok)[:len(X)]
+
+
+def _affine_rows(points_aff):
     enc = F.ints_to_mont_batch
-    X = jnp.asarray(enc([p[0] for p in points_aff] + [0] * pad))
-    Y = jnp.asarray(enc([p[1] for p in points_aff] + [0] * pad))
-    live = np.zeros(b, dtype=bool)
-    live[:n] = True
-    Z = jnp.where(jnp.asarray(live)[:, None],
-                  jnp.broadcast_to(jnp.asarray(F.ONE_MONT), X.shape),
-                  jnp.zeros_like(X)).astype(X.dtype)
-    bits = jnp.broadcast_to(jnp.asarray(_r_order_bits())[None, :], (b, 255))
-    ok = jax.device_get(_g1_subgroup_program(X, Y, Z, bits))
-    return np.asarray(ok)[:n]
+    return enc([p[0] for p in points_aff]), enc([p[1] for p in points_aff])
+
+
+def g1_aggregate_device(points_aff):
+    """g1_aggregate_rows for affine int pairs."""
+    return g1_aggregate_rows(*_affine_rows(points_aff))
+
+
+def g1_subgroup_check_device(points_aff) -> np.ndarray:
+    """g1_subgroup_check_rows for affine int pairs."""
+    return g1_subgroup_check_rows(*_affine_rows(points_aff))
 
 
 # Shape-only cost accounting for the eval_shape pins (tests/test_msm.py),

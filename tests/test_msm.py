@@ -441,3 +441,167 @@ def test_cold_committee_hostile_members_reject_like_host():
     # aggregate_pubkeys_device mirrors AggregatePKs: infinity member raises
     with pytest.raises(ValueError, match="infinity"):
         bls_jax.aggregate_pubkeys_device(pks + [oracle.g1_to_bytes(None)])
+
+
+# --- 5. the validated-key row cache ------------------------------------------
+
+
+def _committee(base: int, n: int = 40):
+    from consensus_specs_tpu.crypto import bls_sig
+
+    sks = [base + i for i in range(n)]
+    return sks, [bytes(bls_sig.SkToPk(sk)) for sk in sks]
+
+
+def _sum_of(sks):
+    return _points([sum(sks) % oracle.R])[0]  # Σ[sk]G == [Σsk]G
+
+
+def _row_counters():
+    return (REG.counter_value("bls_pubkey_row_hits_total"),
+            REG.counter_value("bls_pubkey_subgroup_device_total"),
+            REG.counter_value("bls_pubkey_aggregate_device_keys_total"))
+
+
+def _assert_store_paired():
+    """Every cached key's slot holds that key's point, and its rows are
+    ints_to_mont_batch of that point."""
+    from consensus_specs_tpu.crypto import bls_jax
+    from consensus_specs_tpu.ops import bls12_jax as K
+
+    store = bls_jax._PK_VALIDATED
+    assert len(store) > 0
+    for pk, s in store.slot_of.items():
+        assert store.keys[s] == pk
+        pt = store.points[s]
+        assert pt == oracle.g1_from_bytes(pk, subgroup_check=False)
+        np.testing.assert_array_equal(store.rows[s, 0], K.F.ints_to_mont_batch([pt[0]])[0])
+        np.testing.assert_array_equal(store.rows[s, 1], K.F.ints_to_mont_batch([pt[1]])[0])
+
+
+@pytest.mark.parametrize("case", ["full", "subset", "mix"])
+def test_row_cache_aggregate_matches_oracle(case):
+    """The committee aggregate from cached rows equals the host oracle's
+    sum: the full committee warm, a 39-of-40 (97%) subset, and 20 warm
+    keys with 40 cold ones. Warm keys count as row hits, cold keys as
+    device subgroup checks, and every cached key stays paired."""
+    from consensus_specs_tpu.crypto import bls, bls_jax
+
+    sks, pks = _committee(81001 + 1000 * ["full", "subset", "mix"].index(case))
+    bls.clear_caches()
+    reset_default_scheduler()
+    hits0, sub0, keys0 = _row_counters()
+    assert bls_jax._aggregate_pubkeys_affine(pks) == _sum_of(sks)  # every key cold
+    hits1, sub1, keys1 = _row_counters()
+    assert (hits1 - hits0, sub1 - sub0, keys1 - keys0) == (0, 40, 40)
+
+    if case == "full":  # the committee cache would answer the same list
+        bls_jax._AGG_CACHE.clear()
+        set_sks, set_pks, warm = sks, pks, 40
+    elif case == "subset":
+        set_sks, set_pks, warm = sks[:17] + sks[18:], pks[:17] + pks[18:], 39
+    else:
+        cold_sks, cold_pks = _committee(84001)
+        set_sks, set_pks, warm = sks[::2] + cold_sks, pks[::2] + cold_pks, 20
+    assert bls_jax._aggregate_pubkeys_affine(set_pks) == _sum_of(set_sks)
+    hits2, sub2, keys2 = _row_counters()
+    cold = len(set_pks) - warm
+    assert (hits2 - hits1, sub2 - sub1, keys2 - keys1) == (warm, cold, len(set_pks))
+    assert len(bls_jax._PK_VALIDATED) == 40 + cold
+    _assert_store_paired()
+
+
+def test_row_cache_eviction_keeps_point_and_rows_paired(monkeypatch):
+    """At a cap of 8 keys, slots are reused oldest first: each survivor's
+    point and rows stay its own, and sums over evicted, surviving and new
+    keys stay right."""
+    from consensus_specs_tpu.crypto import bls, bls_jax
+
+    monkeypatch.setattr(bls_jax, "_PK_VALIDATED_MAX", 8)
+    bls.clear_caches()
+    reset_default_scheduler()
+    sks, pks = _committee(85001)
+    assert bls_jax._aggregate_pubkeys_affine(pks) == _sum_of(sks)
+    store = bls_jax._PK_VALIDATED
+    assert set(store.slot_of) == set(pks[-8:])  # the last 8 inserted survive
+    _assert_store_paired()
+
+    new_sks, new_pks = _committee(86001, 34)
+    hits0, sub0, _ = _row_counters()
+    set_sks, set_pks = sks[-8:] + sks[:4] + new_sks, pks[-8:] + pks[:4] + new_pks
+    assert bls_jax._aggregate_pubkeys_affine(set_pks) == _sum_of(set_sks)
+    hits1, sub1, _ = _row_counters()
+    assert (hits1 - hits0, sub1 - sub0) == (8, 38)
+    assert set(store.slot_of) == set(new_pks[-8:])
+    _assert_store_paired()
+
+
+def test_row_cache_keeps_no_rejected_key():
+    """A set with a key outside the r-subgroup, or the infinity key, is
+    rejected and caches none of its keys; a key listed twice takes one
+    slot."""
+    from consensus_specs_tpu.crypto import bls, bls_jax
+
+    bls.clear_caches()
+    reset_default_scheduler()
+    _, pks = _committee(87001, 39)
+    with pytest.raises(ValueError, match="subgroup"):
+        bls_jax._aggregate_pubkeys_affine(pks + [oracle.g1_to_bytes((0, 2))])
+    assert bls_jax._aggregate_pubkeys_affine(pks + [oracle.g1_to_bytes(None)]) is None
+    assert len(bls_jax._PK_VALIDATED) == 0
+
+    sks, pks = _committee(88001, 39)
+    assert bls_jax._aggregate_pubkeys_affine(pks + pks[:1]) == _sum_of(sks + sks[:1])
+    assert len(bls_jax._PK_VALIDATED) == 39
+    _assert_store_paired()
+
+
+def test_clear_caches_drops_points_and_rows():
+    """bls.clear_caches() empties the store: afterwards every key is cold
+    again and is validated on the device anew."""
+    from consensus_specs_tpu.crypto import bls, bls_jax
+    from consensus_specs_tpu.ops import bls12_jax as K
+
+    bls.clear_caches()
+    reset_default_scheduler()
+    sks, pks = _committee(89001)
+    assert bls_jax._aggregate_pubkeys_affine(pks) == _sum_of(sks)
+    assert len(bls_jax._PK_VALIDATED) == 40
+    bls.clear_caches()
+    store = bls_jax._PK_VALIDATED
+    assert len(store) == 0 and store.rows is None and store.points == []
+    assert store.lookup(K.F, pks) == ([None] * 40, None)
+    _, sub0, _ = _row_counters()
+    assert bls_jax._aggregate_pubkeys_affine(pks) == _sum_of(sks)
+    assert _row_counters()[1] - sub0 == 40
+
+
+def test_rows_entries_match_point_wrappers_and_compile_nothing_new():
+    """g1_aggregate_rows / g1_subgroup_check_rows on encoded rows answer
+    as the point-taking wrappers do, and a warm aggregation compiles no
+    program: the row path launches the same aggregation program."""
+    from consensus_specs_tpu.crypto import bls, bls_jax
+    from consensus_specs_tpu.obs.recompile import CompileTracker
+    from consensus_specs_tpu.ops import bls12_jax as K
+
+    sks = [90001 + i for i in range(40)]
+    points = _points(sks)
+    X = K.F.ints_to_mont_batch([p[0] for p in points])
+    Y = K.F.ints_to_mont_batch([p[1] for p in points])
+    assert K.g1_aggregate_rows(X, Y) == K.g1_aggregate_device(points) == _sum_of(sks)
+    assert K.g1_subgroup_check_rows(X, Y).all()
+    bad = points[:39] + [(0, 2)]
+    np.testing.assert_array_equal(K.g1_subgroup_check_device(bad),
+                                  [True] * 39 + [False])
+
+    bls.clear_caches()
+    reset_default_scheduler()
+    c_sks, pks = _committee(91001)
+    assert bls_jax._aggregate_pubkeys_affine(pks) == _sum_of(c_sks)
+    tracker = CompileTracker(registry=obs_metrics.MetricsRegistry()).install()
+    try:
+        before = sum(tracker.kernels().values())
+        assert bls_jax._aggregate_pubkeys_affine(pks[1:]) == _sum_of(c_sks[1:])
+        assert sum(tracker.kernels().values()) == before
+    finally:
+        tracker.uninstall()
