@@ -11,8 +11,10 @@ stance).
 
 Host side (this module): decompression, hash-to-curve, G1 aggregation for
 FastAggregateVerify, padding to bucketed batch shapes (so jit caches stay
-small), and the bool readout. Device side: two Miller loops + shared final
-exponentiation per item.
+small), and the bool readout. Device side: the subgroup checks (pubkeys in
+the msm lane's batched [r]-ladder; a flush's signatures, decompressed on the
+host without the check, in one batched ψ test at the head of run_checks),
+then two Miller loops + shared final exponentiation per item.
 """
 from __future__ import annotations
 
@@ -30,13 +32,13 @@ from .hash_to_curve import hash_to_curve_g2 as _hash_to_curve_g2_uncached
 from .bls12_381 import g2_from_bytes as _g2_from_bytes_uncached
 
 
-# The flush's per-check host prep is dominated by two pure functions, both
-# heavily repeated in real workloads: messages recur across the aggregates
-# of a slot/epoch (same signing root per committee target) and benchmarks
-# replay identical attestation sets, while signature bytes recur whenever
-# the same aggregate is re-verified (gossip + block import). Same caching
-# stance as g1_from_bytes below; entries are a few KB -> both caps stay
-# in the tens of MB.
+# Two pure host functions, both heavily repeated in real workloads:
+# messages recur across the aggregates of a slot/epoch (same signing root
+# per committee target) and benchmarks replay identical attestation sets.
+# g2_from_bytes returns subgroup-checked points only, so the flush, which
+# checks its signatures on the device, decodes them without it
+# (_signature_and_message). Same caching stance as g1_from_bytes below;
+# entries are a few KB -> both caps stay in the tens of MB.
 @lru_cache(maxsize=1 << 13)
 def hash_to_curve_g2(msg: bytes):
     return _hash_to_curve_g2_uncached(msg)
@@ -97,7 +99,9 @@ def _device_check(p1s, q1s, p2s, q2s) -> np.ndarray:
 
 
 class QueuedCheck:
-    """One deferred signature check, normalized to the two-pairing form."""
+    """One deferred signature check, normalized to the two-pairing form.
+    The signature q2 is on the twist but not yet known to lie in G2:
+    run_checks checks it on the device before any pairing."""
 
     __slots__ = ("p1", "q1", "p2", "q2")
 
@@ -106,11 +110,14 @@ class QueuedCheck:
 
 
 def _signature_and_message(message: bytes, signature: bytes):
-    """(H(m)_aff, sig_aff), or None for an invalid signature (the point at
-    infinity is never valid here)."""
+    """(H(m)_aff, sig_aff), or None for an invalid encoding (the point at
+    infinity is never valid here). The signature is decompressed WITHOUT
+    the subgroup check, so it bypasses the validated g2_from_bytes cache:
+    run_checks checks the flush's signatures for G2 membership on the
+    device, in one launch, in place of a 255-bit host ladder each."""
     try:
         with _obs_trace.span("bls.prep.sig_decode"):
-            sig = g2_from_bytes(bytes(signature))
+            sig = oracle.g2_from_bytes(bytes(signature), subgroup_check=False)
     except ValueError:
         return None
     if sig is None:
@@ -556,8 +563,33 @@ def _device_check_all(p1s, q1s, p2s, q2s) -> bool:
     return result
 
 
+def _check_signatures_in_g2(checks: list) -> list:
+    """Every live check's signature through one batched device ψ test
+    (ops/bls12_jax.g2_subgroup_check_device); a check whose signature lies
+    outside G2 becomes None, the verdict False."""
+    from ..ops import bls12_jax as K
+
+    live = [i for i, c in enumerate(checks) if c is not None]
+    if not live:
+        return checks
+    reg = _obs_metrics.REGISTRY
+    with _obs_trace.span("bls.flush.sig_subgroup", sigs=len(live)):
+        ok = K.g2_subgroup_check_device([checks[i].q2 for i in live])
+    reg.counter("bls_sig_subgroup_device_total").inc(len(live))
+    bad = [i for i, good in zip(live, ok) if not good]
+    if not bad:
+        return checks
+    reg.counter("bls_sig_subgroup_rejected_total").inc(len(bad))
+    checks = list(checks)
+    for i in bad:
+        checks[i] = None
+    return checks
+
+
 def run_checks(checks) -> np.ndarray:
-    """Execute a list of QueuedCheck | None on device; None -> False."""
+    """Execute a list of QueuedCheck | None on device; None -> False. The
+    signatures are checked for G2 membership first, in one launch."""
+    checks = _check_signatures_in_g2(checks)
     live = [(i, c) for i, c in enumerate(checks) if c is not None]
     out = np.zeros(len(checks), dtype=bool)
     if not live:

@@ -1351,6 +1351,30 @@ def _g1_subgroup_program(X, Y, Z, bits):
     return F.fp_is_zero(g1_scalar_mul_batch((X, Y, Z), bits)[2])
 
 
+@jax.jit
+def _g2_subgroup_program(rows, bits, psi):
+    """ψ(Q_i) == [x]·Q_i per item: G2 membership of points on the twist
+    (Scott, IACR ePrint 2021/1130). `rows` is (6, n, NLIMBS): Jacobian X,
+    Y, Z as (re, im) pairs, Z = 1 on live items; `bits` the 64 bits of |x|;
+    `psi` (4, NLIMBS) the ψ constants cx, cy as (re, im) pairs. x < 0, so
+    the test is [|x|]Q == −ψ(Q) with ψ(Q) = (conj(qx)·cx, conj(qy)·cy),
+    compared projectively: X' == ψx·Z'^2, Y' == −ψy·Z'^3 and Z' ≠ 0. No
+    point is added to the result and no infinity tested, so no special case
+    of the complete add can pass a point outside G2."""
+    X, Y, Z = (rows[0], rows[1]), (rows[2], rows[3]), (rows[4], rows[5])
+    bits = jnp.broadcast_to(bits, X[0].shape[:1] + bits.shape)
+    QX, QY, QZ = g2_scalar_mul_batch((X, Y, Z), bits)
+    psx = f2_mul(f2_conj(X), (psi[0], psi[1]))
+    psy = f2_mul(f2_conj(Y), (psi[2], psi[3]))
+    z2 = f2_sqr(QZ)
+    return (f2_is_zero(f2_sub(QX, f2_mul(psx, z2)))
+            & f2_is_zero(f2_add(QY, f2_mul(psy, f2_mul(QZ, z2))))
+            & ~f2_is_zero(QZ))
+
+
+_ABS_X_BITS = np.array([(ABS_X >> i) & 1 for i in range(64)], dtype=bool)
+
+
 @lru_cache(maxsize=1)
 def _r_order_bits():
     # NUMPY, not jnp: cached module constant, same trace-leak stance as
@@ -1435,6 +1459,24 @@ def g1_subgroup_check_rows(X, Y) -> np.ndarray:
     bits = jnp.asarray(np.broadcast_to(_r_order_bits(), (Xp.shape[0], 255)))
     ok = jax.device_get(_g1_subgroup_program(Xp, Yp, Zp, bits))
     return np.asarray(ok)[:len(X)]
+
+
+def g2_subgroup_check_device(points_aff) -> np.ndarray:
+    """G2 membership (the ψ test) per affine twist point ((x0, x1), (y0,
+    y1)), batched: (n,) bool. The coordinates go in as Montgomery rows,
+    padded with all-zero rows to the pow2 bucket; one launch, and the pads'
+    results are discarded."""
+    from ..crypto.hash_to_curve import _PSI_CX, _PSI_CY
+
+    n = len(points_aff)
+    coords = [p[i][j] for i in (0, 1) for j in (0, 1) for p in points_aff]
+    enc = F.ints_to_mont_batch(coords)
+    rows = np.zeros((6, _msm_pow2_pad(n), F.NLIMBS), dtype=enc.dtype)
+    rows[:4, :n] = enc.reshape(4, n, F.NLIMBS)
+    rows[4, :n] = F.ONE_MONT
+    psi = F.ints_to_mont_batch([*_PSI_CX, *_PSI_CY])
+    ok = jax.device_get(_g2_subgroup_program(rows, _ABS_X_BITS, psi))
+    return np.asarray(ok)[:n]
 
 
 def _affine_rows(points_aff):
